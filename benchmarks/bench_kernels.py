@@ -44,7 +44,7 @@ def run():
     # elastic kernel: correctness + tile-skip work ratio
     for frac in (1.0, 0.5, 0.25):
         ka, na = int(K * frac), int(N * frac)
-        y = elastic_matmul_op(x, w, ka, na)
+        y = elastic_matmul_op(x, w, ka, na, interpret=True)
         yr = elastic_matmul_ref(x, w, ka, na)
         err = float(jnp.max(jnp.abs(y - yr)))
         live_tiles = -(-ka // 128) * -(-na // 128)

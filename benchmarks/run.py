@@ -129,6 +129,7 @@ def main() -> None:
     import benchmarks.bench_switching as bs
     import benchmarks.bench_traffic as bt
     import benchmarks.roofline_table as rt
+    from repro.launch.cache import use_compile_cache
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
@@ -141,6 +142,7 @@ def main() -> None:
     ap.add_argument("--suite", metavar="SUBSTR", default=None,
                     help="run only suites whose title contains SUBSTR")
     args = ap.parse_args()
+    use_compile_cache()
 
     suites = [
         ("pareto (paper: Dynamic-OFA vs static)", bp.run),

@@ -53,7 +53,7 @@ from repro.kernels.ref import elastic_matmul_ref
 
 xm = jax.random.normal(jax.random.PRNGKey(1), (128, 512))
 wm = jax.random.normal(jax.random.PRNGKey(2), (512, 512))
-y = elastic_matmul_op(xm, wm, 256, 384)
+y = elastic_matmul_op(xm, wm, 256, 384, interpret=True)
 yr = elastic_matmul_ref(xm, wm, 256, 384)
 print(f"\nelastic_matmul kernel vs oracle: "
       f"max_err={float(jnp.max(jnp.abs(y - yr))):.2e}")

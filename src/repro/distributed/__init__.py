@@ -1,3 +1,2 @@
 """Distribution substrate: mesh context, sharding rules, collectives."""
-from repro.distributed.ctx import (current_mesh, shard_map, use_mesh, wsc,
-                                   batch_axes)
+from repro.distributed.ctx import current_mesh, use_mesh, wsc, batch_axes

@@ -1,10 +1,5 @@
 """Ambient mesh context so model code can hint shardings without
 hard-coding a mesh (single-device tests run with no mesh at all).
-
-Also the version-compat home for ``shard_map``: the top-level
-``jax.shard_map`` (and its ``check_vma`` kwarg) only exist on newer JAX;
-the 0.4.37 floor has ``jax.experimental.shard_map.shard_map`` with
-``check_rep`` instead.
 """
 from __future__ import annotations
 
@@ -15,15 +10,6 @@ from typing import Optional, Tuple
 import jax
 from jax.sharding import PartitionSpec as P
 
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable shard_map (``check_vma`` maps to old ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
 
 _MESH: contextvars.ContextVar = contextvars.ContextVar("repro_mesh", default=None)
 

@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.ctx import shard_map
-
 
 def sharded_decode_attention(q, k_new, v_new, cache_k, cache_v, cache_len, *,
                              mesh, seq_axes=("model",),
@@ -85,7 +83,7 @@ def sharded_decode_attention(q, k_new, v_new, cache_k, cache_v, cache_len, *,
 
     cache_spec = P(bspec, seq_axes, None, None)
     rep4 = P(bspec, None, None, None)
-    out, ck, cv = shard_map(
+    out, ck, cv = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, None, None, None, None), rep4, rep4,
                   cache_spec, cache_spec, P()),

@@ -1,8 +1,9 @@
 """jit'd public wrappers around the Pallas kernels (padding + reshaping).
 
-``interpret`` defaults to True off-TPU so the same call sites work in this
-CPU container (the kernel body executes in Python) and compile to Mosaic
-on a real TPU.
+The kernels compile to Mosaic for a TPU.  ``interpret=True`` runs the
+kernel body in Python instead, on any backend; callers without a TPU
+(tests, CPU examples) must ask for it explicitly, so a missing chip fails
+loudly instead of silently running the slow interpreter.
 """
 from __future__ import annotations
 
@@ -13,10 +14,6 @@ import jax.numpy as jnp
 
 from repro.kernels.elastic_matmul import elastic_matmul
 from repro.kernels.flash_attention import flash_attention
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_to(x, axis, mult):
@@ -31,9 +28,8 @@ def _pad_to(x, axis, mult):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
 def elastic_matmul_op(x, w, k_act, n_act, *, bm=128, bk=128, bn=128,
-                      interpret=None):
+                      interpret: bool = False):
     """Batched elastic matmul: x (..., K) @ w (K, N) with runtime widths."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
     lead = x.shape[:-1]
     K = x.shape[-1]
     N = w.shape[-1]
@@ -52,9 +48,8 @@ def elastic_matmul_op(x, w, k_act, n_act, *, bm=128, bk=128, bn=128,
 @functools.partial(jax.jit,
                    static_argnames=("causal", "bq", "bkv", "interpret"))
 def flash_attention_op(q, k, v, *, causal=True, bq=256, bkv=256,
-                       interpret=None):
+                       interpret: bool = False):
     """q (B, S, H, D), k/v (B, T, KH, D) -> (B, S, H, D). GQA repeats kv."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
     B, S, H, D = q.shape
     _, T, KH, _ = k.shape
     if KH != H:

@@ -3,48 +3,19 @@
 Functions, not module-level constants — importing this module never touches
 jax device state.  The dry-run (and only the dry-run) forces 512 host
 devices via XLA_FLAGS before any jax import.
-
-Version compat: ``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg of
-``jax.make_mesh``) only exist on newer JAX; the floor this repo supports is
-0.4.37, where ``jax.make_mesh`` exists but takes no ``axis_types``.  All
-mesh construction goes through :func:`make_mesh` so the rest of the code
-(and the tests) never touch the version-dependent surface.
 """
 from __future__ import annotations
 
 import jax
-
-
-def _auto_axis_types(n_axes: int):
-    """``(AxisType.Auto,) * n_axes`` on JAX versions that have it, else None."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n_axes
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """Version-portable ``jax.make_mesh`` (tests use (1,1)/(2,2) CPU meshes).
-
-    Passes ``axis_types=Auto`` where supported; on JAX 0.4.x (no
-    ``AxisType``) it falls back to a plain mesh, which has the same Auto
-    semantics there.  Falls back again to a hand-built ``Mesh`` if
-    ``jax.make_mesh`` itself is absent (pre-0.4.35).
-    """
-    shape = tuple(shape)
-    axes = tuple(axes)
-    axis_types = _auto_axis_types(len(axes))
-    if hasattr(jax, "make_mesh"):
-        kwargs = {}
-        if devices is not None:
-            kwargs["devices"] = devices
-        if axis_types is not None:
-            kwargs["axis_types"] = axis_types
-        return jax.make_mesh(shape, axes, **kwargs)
-    import numpy as np
-    devs = np.asarray(devices if devices is not None
-                      else jax.devices()[: int(np.prod(shape))])
-    return jax.sharding.Mesh(devs.reshape(shape), axes)
+    """``jax.make_mesh`` with every axis ``Auto``: model code hints shardings
+    with ``with_sharding_constraint`` and leaves propagation to XLA."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

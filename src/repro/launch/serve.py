@@ -20,7 +20,9 @@ Serving data-path knobs (mirrored by ``DynamicServer``):
 Cluster / trace knobs (``--trace`` mode):
 
 * ``--nodes N``       — scale the SLO classes out over N arbiter-governed
-  nodes behind the cluster front-end (``repro.cluster``);
+  nodes behind the cluster front-end (``repro.cluster``); node i serves
+  from ``jax.devices()[i % n]``, so N one-chip replicas on an N-chip host
+  each hold their own chip;
 * ``--router p2c|round_robin|least_loaded`` — the routing policy;
 * ``--record PATH``   — save the ACTUAL arrivals as a replayable
   schedule JSON (feed it back via ``--trace PATH``);
@@ -45,7 +47,10 @@ Observability (any mode):
 
 The governed server warms its bucket ladder for the profiled subnets
 before taking traffic, so steady-state serving performs zero cold
-compiles (``server.cold_compiles`` stays 0).
+compiles (``server.cold_compiles`` stays 0).  The first line names the
+device; compiled executables persist in the directory
+``repro.launch.cache`` picks.  In plain mode a failed request fails the
+run (non-zero exit).
 """
 from __future__ import annotations
 
@@ -57,6 +62,7 @@ import numpy as np
 
 from repro.configs import get_arch
 from repro.core.types import SubnetSpec
+from repro.launch.cache import use_compile_cache
 from repro.obs import (MetricsRegistry, TraceStreamer, Tracer, Watchtower,
                        decompose_latency, default_windows,
                        format_alerts, format_decomposition, format_profile,
@@ -70,12 +76,23 @@ from repro.runtime import (CalibrationStore, Constraints, DynamicServer,
 from repro.runtime import hwmodel as hm
 
 
+def device_info() -> dict:
+    """The device this process serves on, as JAX reports it."""
+    devices = jax.devices()
+    return {"platform": devices[0].platform, "kind": devices[0].device_kind,
+            "count": len(devices)}
+
+
 def build_server(arch, cfg, *, max_batch=8, batch_buckets=True,
-                 pipeline=True, calibration=None, tenant=None):
+                 pipeline=True, calibration=None, tenant=None, device=None):
+    """One DynamicServer over seeded weights; ``device`` pins its params
+    (and so every executable it dispatches) to that device."""
     key = jax.random.PRNGKey(0)
     if arch.arch_id.startswith(("deit", "vit", "dynamic-ofa")):
         from repro.models.vit import vit_apply, vit_init
         params = vit_init(key, cfg)
+        if device is not None:
+            params = jax.device_put(params, device)
         dims = {"d_model": cfg.d_model, "d_ff": cfg.d_ff,
                 "n_heads": cfg.n_heads, "n_layers": cfg.n_layers}
         apply_fn = lambda p, x, E: vit_apply(p, x, cfg, E=E)[0]
@@ -85,6 +102,23 @@ def build_server(arch, cfg, *, max_batch=8, batch_buckets=True,
     return DynamicServer(apply_fn, params, dims, max_batch=max_batch,
                          batch_buckets=batch_buckets, pipeline=pipeline,
                          calibration=calibration, tenant=tenant)
+
+
+def measure_lut(server, specs, x, hw_states=None):
+    """LUT of ``specs`` measured on ``server`` at batch ``len(x)``.
+
+    Each subnet is timed once at full frequency; every frequency tier
+    scales that time by ``1 / hw.freq`` and prices it at the tier's
+    modelled power, so the tiers of one subnet never disagree on rank."""
+    ms = {}
+
+    def measure(spec, hw):
+        if spec not in ms:
+            ms[spec] = server.measure(spec, x)
+        lat = ms[spec] / hw.freq
+        return lat, hm.step_energy_mj(hm.RooflineTerms(lat / 1e3, 0.0, 0.0),
+                                      hw)
+    return measured_lut(specs, measure, hw_states=hw_states)
 
 
 def run_trace_mode(args, arch, cfg, server, lut, x, base_ms):
@@ -150,6 +184,7 @@ def run_trace_mode(args, arch, cfg, server, lut, x, base_ms):
 
     if args.nodes > 1:
         from repro.cluster import Cluster, ClusterNode
+        devices = jax.devices()
         nodes = [ClusterNode(name=f"node{i}",
                              g_fn=lambda t: GlobalConstraints(total_chips=2))
                  for i in range(args.nodes)]
@@ -163,10 +198,12 @@ def run_trace_mode(args, arch, cfg, server, lut, x, base_ms):
 
         for c in classes:
             def mk_server(node, _name=c.name):
+                # node i serves from device i (mod the devices present)
+                dev = devices[nodes.index(node) % len(devices)]
                 s = build_server(arch, cfg, max_batch=server.max_batch,
                                  batch_buckets=server.batch_buckets,
                                  pipeline=server.pipeline,
-                                 calibration=store, tenant=_name)
+                                 calibration=store, tenant=_name, device=dev)
                 s.warm(warm, example_input=x[0])
                 return s
 
@@ -342,6 +379,8 @@ def main(argv=None):
                     help="synchronous dispatch (no host/device overlap)")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
+    print(f"device: {device_info()}")
     arch = get_arch(args.arch)
     cfg = arch.make_smoke() if args.smoke else arch.make_config()
     server = build_server(arch, cfg, max_batch=args.max_batch,
@@ -356,12 +395,7 @@ def main(argv=None):
         size=(server.max_batch, cfg.img_res, cfg.img_res, 3)).astype(np.float32)
 
     # measured LUT on this host (freq modelled; latency real wall-clock)
-    def measure(spec, hw):
-        lat = server.measure(spec, x) / hw.freq
-        terms = hm.RooflineTerms(lat / 1e3, 0.0, 0.0)
-        return lat, hm.step_energy_mj(terms, hw)
-
-    lut = measured_lut(specs, measure)
+    lut = measure_lut(server, specs, x)
     print(f"profiled {len(lut.points)} operating points over "
           f"{len(specs)} subnets")
 
@@ -407,6 +441,12 @@ def main(argv=None):
     futs = [server.submit(x[0]) for _ in range(args.requests)]
     outs = [f.get(timeout=30) for f in futs]
     server.stop()
+    # the engine answers a failed batch or dispatch with an error payload
+    # so callers never hang; here a failure must fail the run
+    errors = [o["error"] for o in outs if o.get("error")]
+    if errors:
+        raise SystemExit(f"{len(errors)}/{len(outs)} requests failed: "
+                         f"{errors[0]}")
     lats = [o["latency_ms"] for o in outs]
     print(f"\nserved {len(outs)} requests  p50={quantile(lats,50):.1f}ms "
           f"p99={quantile(lats,99):.1f}ms  "

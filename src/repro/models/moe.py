@@ -28,7 +28,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core.elastic import active_mask, take_dim
 from repro.core.layers import dense_init, mlp_init, mlp_apply
 from repro.core.types import is_static
-from repro.distributed.ctx import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -245,7 +244,7 @@ def moe_apply(p: dict, x: jax.Array, cfg: MoEConfig, *,
             return y.reshape(xl.shape), jnp.array([[aux]])  # keep shard dims
 
         batch_spec = P(tuple(data_axes), ax, None)
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(None, None), P(ax, None, None), P(ax, None, None),
                       P(ax, None, None), batch_spec),

@@ -271,8 +271,17 @@ class DynamicServer:
         return jax.block_until_ready(fn(self.params, x))
 
     def measure(self, spec: SubnetSpec, x, iters: int = 5) -> float:
-        """Median wall-clock ms for one batch under ``spec`` (post-warmup)."""
+        """Median wall-clock ms for one batch under ``spec`` (post-warmup).
+
+        ``x`` goes to the params' device once, before timing: from host
+        memory every call would also pay the input copy, whose cost and
+        jitter can outweigh the gap between subnets and misrank them."""
         fn = self.executable(spec)
+        leaves = jax.tree_util.tree_leaves(self.params)
+        devices = (leaves[0].devices() if leaves
+                   and isinstance(leaves[0], jax.Array) else set())
+        if len(devices) == 1:
+            x = jax.device_put(x, next(iter(devices)))
         jax.block_until_ready(fn(self.params, x))
         ts = []
         for _ in range(iters):

@@ -98,11 +98,14 @@ def test_executable_cache_thread_safe():
     assert all(len(ids) == 1 for ids in by_spec.values())
 
 
-def test_mesh_compat_no_axis_type():
-    """make_mesh works on JAX versions without jax.sharding.AxisType."""
+def test_make_mesh_axis_types_auto():
+    """make_mesh gives every axis the Auto type the sharding hints need."""
+    from jax.sharding import AxisType
+
     from repro.launch.mesh import make_mesh
     mesh = make_mesh((1, 1), ("data", "model"))
     assert dict(mesh.shape) == {"data": 1, "model": 1}
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
 
 
 def test_hypothesis_importable_everywhere():

@@ -23,7 +23,7 @@ def test_elastic_matmul_sweep(dtype, ka, na):
     x = jax.random.normal(KEY, (64, 256), jnp.float32).astype(dtype)
     w = jax.random.normal(jax.random.fold_in(KEY, 1), (256, 384),
                           jnp.float32).astype(dtype)
-    y = elastic_matmul_op(x, w, ka, na, bm=32)
+    y = elastic_matmul_op(x, w, ka, na, bm=32, interpret=True)
     yr = elastic_matmul_ref(x, w, ka, na)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4
     np.testing.assert_allclose(np.asarray(y, np.float32),
@@ -35,7 +35,8 @@ def test_elastic_matmul_sweep(dtype, ka, na):
 def test_elastic_matmul_property(m, k_act, n_act):
     x = jax.random.normal(KEY, (m, 256))
     w = jax.random.normal(jax.random.fold_in(KEY, 2), (256, 384))
-    y = elastic_matmul_op(x, w, k_act, n_act, bm=32)
+    y = elastic_matmul_op(x, w, k_act, n_act, bm=32,
+                          interpret=True)
     yr = elastic_matmul_ref(x, w, k_act, n_act)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                rtol=3e-4, atol=3e-4)
@@ -46,12 +47,21 @@ def test_elastic_matmul_traced_widths_one_executable():
     """The widths are traced: one jit covers every (k_act, n_act)."""
     x = jax.random.normal(KEY, (32, 256))
     w = jax.random.normal(KEY, (256, 256))
-    f = jax.jit(lambda ka, na: elastic_matmul_op(x, w, ka, na, bm=32))
+    f = jax.jit(lambda ka, na: elastic_matmul_op(x, w, ka, na, bm=32,
+                                                 interpret=True))
     for ka, na in [(256, 256), (64, 128), (10, 250)]:
         np.testing.assert_allclose(
             np.asarray(f(ka, na)),
             np.asarray(elastic_matmul_ref(x, w, ka, na)),
             rtol=3e-4, atol=3e-4)
+
+
+def test_elastic_matmul_needs_explicit_interpret_off_tpu():
+    """On the CPU the kernel refuses to run unless interpret mode is asked
+    for: nothing falls back to the Python interpreter in silence."""
+    x = jnp.ones((32, 256))
+    with pytest.raises(ValueError, match="interpret"):
+        elastic_matmul_op(x, x.T, 256, 32, bm=32)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -66,7 +76,8 @@ def test_flash_attention_sweep(dtype, causal, S, T, H, KH, D):
                            jnp.float32) * 0.3).astype(dtype)
     v = jax.random.normal(jax.random.fold_in(KEY, 2), (B, T, KH, D),
                           jnp.float32).astype(dtype)
-    o = flash_attention_op(q, k, v, causal=causal, bq=128, bkv=128)
+    o = flash_attention_op(q, k, v, causal=causal, bq=128, bkv=128,
+                           interpret=True)
     kr = jnp.repeat(k, H // KH, 2)
     vr = jnp.repeat(v, H // KH, 2)
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, S, D)
@@ -84,7 +95,8 @@ def test_flash_attention_long_context_block_sizes():
     q = jax.random.normal(KEY, (1, 128, 2, 64)) * 0.3
     k = jax.random.normal(jax.random.fold_in(KEY, 1), (1, 1024, 2, 64)) * 0.3
     v = jax.random.normal(jax.random.fold_in(KEY, 2), (1, 1024, 2, 64))
-    o = flash_attention_op(q, k, v, causal=False, bq=64, bkv=256)
+    o = flash_attention_op(q, k, v, causal=False, bq=64, bkv=256,
+                           interpret=True)
     qf = q.transpose(0, 2, 1, 3).reshape(2, 128, 64)
     kf = k.transpose(0, 2, 1, 3).reshape(2, 1024, 64)
     vf = v.transpose(0, 2, 1, 3).reshape(2, 1024, 64)
