@@ -1,0 +1,9 @@
+"""Share of the traced window with no operation on the device while a
+dispatched batch of its engine was not yet device-ready; each chip
+against its own node's batch spans, mean of the chips."""
+from bench import spans
+
+
+def read(run):
+    split = spans.idle_split(run)
+    return None if split is None else split["await"]

@@ -1,0 +1,8 @@
+"""The collector threads' CPU time over the wall time of stack + dispatch
+(batch spans' ``cpu_stack_s``), over the four replicas' batches stacked
+inside the window: low means the collectors waited."""
+from bench import spans
+
+
+def read(run):
+    return spans.host_cpu_pct(run)

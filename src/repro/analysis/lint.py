@@ -24,7 +24,8 @@ Rules (see ROADMAP.md "Runtime invariants" for rationale):
   threading is exempt (explicitly seeded by construction).
 * **RT004 span-schema** — span emission call sites
   (``tracer.decision(KIND, ...)``, ``add_span(tid, KIND, ...)``,
-  ``spans=[(KIND, t0, t1, attrs)]``) checked against
+  ``spans=[(KIND, t0, t1, attrs)]``, ``tracer.batch(t0, t1, ...)``)
+  checked against
   ``repro.obs.trace.SCHEMA``: the kind must exist and the required
   attributes must be present in the literal attrs.  Catches
   schema drift at lint time instead of in a Perfetto viewer.
@@ -161,6 +162,12 @@ def _load_schema():
         if name.isupper() and isinstance(val, str)
     }
     return schema, consts
+
+
+def _names_tracer(node: ast.AST) -> bool:
+    """``tracer`` or ``<x>.tracer``: the receiver of a span call."""
+    return ((isinstance(node, ast.Name) and node.id == "tracer")
+            or (isinstance(node, ast.Attribute) and node.attr == "tracer"))
 
 
 def _resolve_kind(node: ast.AST, consts: Dict[str, str]) -> Optional[str]:
@@ -509,6 +516,8 @@ class _FileLint(ast.NodeVisitor):
             self._check_span_call(node, fn.attr)
         if fn.attr in ("request", "finish_request"):
             self._check_spans_kwarg(node)
+        if fn.attr == "batch" and _names_tracer(base):
+            self._check_kw_attrs(node, "batch", "'tracer.batch()'")
         # RT005: Thread without daemon via threading.Thread(...)
         if (fn.attr == "Thread" and isinstance(base, ast.Name)
                 and base.id in self.threading_names):
@@ -574,14 +583,17 @@ class _FileLint(ast.NodeVisitor):
                                   f"'{method}()'")
         if res is None:
             return
-        kind, required = res
+        self._check_kw_attrs(node, res[0], f"'{method}({res[0]})'")
+
+    def _check_kw_attrs(self, node: ast.Call, kind: str, what: str) -> None:
+        required = self.schema.get(kind, ())
         if any(kw.arg is None for kw in node.keywords):
             return  # **attrs: can't see inside
         present = {kw.arg for kw in node.keywords}
         missing = [a for a in required if a not in present]
         if missing:
             self._emit("RT004", node.lineno,
-                       f"'{method}({kind})' missing required attr(s) "
+                       f"{what} missing required attr(s) "
                        f"{missing} per SCHEMA")
 
     def _check_spans_kwarg(self, node: ast.Call) -> None:

@@ -65,8 +65,8 @@ from repro.core.types import SubnetSpec
 from repro.launch.cache import use_compile_cache
 from repro.obs import (MetricsRegistry, TraceStreamer, Tracer, Watchtower,
                        decompose_latency, default_windows,
-                       format_alerts, format_decomposition, format_profile,
-                       profile_devices, quantile, write_chrome_trace)
+                       format_alerts, format_decomposition, quantile,
+                       write_chrome_trace)
 from repro.runtime import (CalibrationStore, Constraints, DynamicServer,
                            GlobalConstraints, JointGovernor, Monitor,
                            PerformanceGovernor, ResourceArbiter,
@@ -136,8 +136,7 @@ def run_trace_mode(args, arch, cfg, server, lut, x, base_ms):
     from repro.traffic import (DEGRADE, SLOClass, drive_live, load_schedule,
                                onoff, poisson)
 
-    need_tracer = (args.trace_out or args.stream_trace or args.profile_out
-                   or args.alerts_out)
+    need_tracer = args.trace_out or args.stream_trace or args.alerts_out
     tracer = Tracer() if need_tracer else None
     metrics = (MetricsRegistry()
                if (args.metrics_out or args.alerts_out) else None)
@@ -269,9 +268,9 @@ def run_trace_mode(args, arch, cfg, server, lut, x, base_ms):
 
 
 def _emit_obs(args, tracer, metrics, watchtower=None, streamer=None):
-    """Write --trace-out / --metrics-out / --alerts-out / --profile-out
-    artifacts, close the --stream-trace stream, and print the per-class
-    latency decomposition for the retained traces."""
+    """Write --trace-out / --metrics-out / --alerts-out artifacts, close
+    the --stream-trace stream, and print the per-class latency
+    decomposition for the retained traces."""
     if streamer is not None:
         n = streamer.close(tracer)
         print(f"  streamed {n} trace events -> {streamer.path}")
@@ -289,12 +288,6 @@ def _emit_obs(args, tracer, metrics, watchtower=None, streamer=None):
         print(f"  {len(watchtower.alerts)} SLO alerts "
               f"(time-in-SLO {watchtower.summary()['time_in_slo']}) "
               f"-> {args.alerts_out}")
-    if tracer is not None and getattr(args, "profile_out", None):
-        prof = profile_devices(tracer)
-        with open(args.profile_out, "w") as f:
-            f.write(format_profile(prof) + "\n")
-        print(f"  device profile: {len(prof)} (subnet, bucket) rows "
-              f"-> {args.profile_out}")
     if metrics is not None and args.metrics_out:
         text = (metrics.to_prometheus()
                 if args.metrics_out.endswith(".prom")
@@ -367,10 +360,6 @@ def main(argv=None):
                     help="--trace mode: run the SLO watchtower (burn-rate "
                          "alerts + attribution) against the live run and "
                          "write the alert log to PATH")
-    ap.add_argument("--profile-out", default=None, metavar="PATH",
-                    help="write the per-(subnet, bucket) device profile "
-                         "(analytic FLOPs, MXU utilisation, roofline "
-                         "position) from retained DEVICE spans to PATH")
     ap.add_argument("--max-batch", type=int, default=8,
                     help="batching ceiling (bucket ladder = powers of two)")
     ap.add_argument("--no-buckets", action="store_true",
@@ -427,8 +416,7 @@ def main(argv=None):
     constraints = lambda: Constraints(target_latency_ms=base_ms,
                                       chips_available=1)
     server.governor = gov
-    tracer = (Tracer() if (args.trace_out or args.stream_trace
-                           or args.profile_out) else None)
+    tracer = Tracer() if (args.trace_out or args.stream_trace) else None
     metrics = MetricsRegistry() if args.metrics_out else None
     streamer = (TraceStreamer(args.stream_trace).attach(tracer)
                 if args.stream_trace else None)

@@ -30,7 +30,9 @@ What's inside:
   uniform sample); the fixed span vocabulary and its :data:`SCHEMA`
   (``request → route → queue → collect → stack → dispatch → device →
   complete`` plus ``arbitrate`` / ``rebalance`` / ``migrate`` /
-  ``preempt`` / ``scale`` / ``health_fail`` decision spans).
+  ``preempt`` / ``scale`` / ``health_fail`` decision spans, and one
+  ``batch`` span per batch the engine dispatched);
+  :func:`current_tracer`, the newest Tracer of the process.
 * ``metrics``  — :class:`MetricsRegistry`: counters / gauges /
   fixed-bucket histograms with labels, Prometheus-text + JSON export,
   and the one shared nearest-rank :func:`quantile` every percentile in
@@ -50,9 +52,6 @@ What's inside:
   and decision spans) and histogram-bucket exemplars; its
   :meth:`Watchtower.pressure` signal closes the monitor→diagnose→
   actuate loop through the arbiter and rebalancer.
-* ``profile``  — analytic device profiling: retained DEVICE spans
-  joined with the analytic FLOPs/bytes model into per-(subnet, bucket)
-  MXU utilisation and roofline position.
 
 Design rules: stdlib-only (imported by every layer — must never cycle
 or pull in jax); ``tracer=None`` everywhere means zero work on the hot
@@ -69,18 +68,17 @@ from repro.obs.health import (FAST, PAGE, SLOW, TICKET, Alert, Attribution,
 from repro.obs.metrics import (DEFAULT_BUCKETS_MS, Counter, Gauge,
                                Histogram, MetricsRegistry, quantile,
                                weighted_quantile)
-from repro.obs.profile import (export_profile, format_profile,
-                               profile_devices)
 from repro.obs.stream import TraceStreamer
-from repro.obs.trace import (ARBITRATE, BROWNOUT, CHAOS, COLLECT, COMPLETE,
-                             COMPONENTS, DECISION_SPANS, DEVICE, DISPATCH,
-                             HEALTH_FAIL, MIGRATE, PREEMPT, QUEUE, REBALANCE,
-                             REQUEST_SPANS, ROUTE, SCALE, SCHEMA, STACK,
-                             WARMING, RequestTrace, Span, Tracer,
-                             validate_schema)
+from repro.obs.trace import (ARBITRATE, BATCH, BROWNOUT, CHAOS, COLLECT,
+                             COMPLETE, COMPONENTS, DECISION_SPANS, DEVICE,
+                             DISPATCH, HEALTH_FAIL, MIGRATE, PREEMPT, QUEUE,
+                             REBALANCE, REQUEST_SPANS, ROUTE, SCALE, SCHEMA,
+                             STACK, WARMING, RequestTrace, Span, Tracer,
+                             current_tracer, validate_schema)
 
 __all__ = [
     "Tracer", "Span", "RequestTrace", "SCHEMA", "COMPONENTS",
+    "current_tracer", "BATCH",
     "REQUEST_SPANS", "DECISION_SPANS", "validate_schema",
     "ROUTE", "QUEUE", "COLLECT", "STACK", "DISPATCH", "DEVICE",
     "COMPLETE", "WARMING", "ARBITRATE", "REBALANCE", "MIGRATE",
@@ -94,5 +92,4 @@ __all__ = [
     "Watchtower", "Alert", "Attribution", "Cause", "SLOTarget",
     "BurnWindow", "default_windows", "format_alerts",
     "FAST", "SLOW", "PAGE", "TICKET",
-    "profile_devices", "format_profile", "export_profile",
 ]

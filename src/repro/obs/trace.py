@@ -22,18 +22,29 @@ keys it must carry):
   ``rebalance``, ``migrate`` (with its real warmup duration),
   ``preempt``, ``scale``, ``health_fail``.
 
+* one ``batch`` span per batch the engine dispatched: first request
+  taken off the queue -> futures resolved, with every stage stamp, the
+  requests' submit and take times and the collector thread's CPU time
+  (:meth:`Tracer.batch`).
+
 **Retention** is bounded and tail-biased: finished request trees land in
 a fixed-capacity buffer that always keeps the globally slowest
 ``tail_frac`` share (a min-heap on total latency — the p99 outlier that
 motivated the trace is never evicted) plus a seeded uniform reservoir
 sample of the rest, so percentile *decomposition* stays honest while
 memory stays O(capacity).  Decision spans go to a separate capped deque
-with a ``decisions_dropped`` counter (the PR-3 ``switch_log`` idiom).
+with a ``decisions_dropped`` counter (the PR-3 ``switch_log`` idiom);
+batch spans to another, with ``batches_dropped``.
 
-Overhead: recording is a handful of dataclass constructions and one
-lock acquisition per finished request (the engine batches a request's
-whole span list into a single call); with no tracer attached the
-instrumented code paths do nothing.
+Overhead: recording is a handful of slotted dataclass constructions and
+one lock acquisition per finished request (the engine batches a
+request's whole span list into a single call; a span with no attrs
+shares one empty mapping); with no tracer attached the instrumented
+code paths do nothing.
+
+:func:`current_tracer` returns the Tracer most recently built in the
+process, so code in the same process (a benchmark's metric reader, an
+operator's tool) finds the spans without a handle threaded through.
 """
 from __future__ import annotations
 
@@ -43,7 +54,9 @@ import heapq
 import random
 import threading
 import time
-from typing import Deque, Dict, Iterable, List, Optional, Sequence, Tuple
+import types
+from typing import (Deque, Dict, Iterable, List, Mapping, Optional, Sequence,
+                    Tuple)
 
 # --- span vocabulary (fixed) -------------------------------------------------
 
@@ -67,6 +80,9 @@ SCALE = "scale"
 HEALTH_FAIL = "health_fail"
 CHAOS = "chaos"         # one injected fault landing (repro.chaos)
 BROWNOUT = "brownout"   # a class entering/exiting degraded-target mode
+
+# engine span: one dispatched batch, first take -> futures resolved
+BATCH = "batch"
 
 REQUEST_SPANS = (ROUTE, QUEUE, COLLECT, STACK, DISPATCH, DEVICE, COMPLETE,
                  WARMING)
@@ -97,10 +113,14 @@ SCHEMA: Dict[str, Tuple[str, ...]] = {
     HEALTH_FAIL: (),
     CHAOS: ("kind",),
     BROWNOUT: ("direction",),
+    BATCH: ("bucket", "subnet", "n"),
 }
 
+# the attrs of every span recorded without any: one shared, read-only
+_NO_ATTRS: Mapping = types.MappingProxyType({})
 
-@dataclasses.dataclass
+
+@dataclasses.dataclass(slots=True)
 class Span:
     """One timed interval.  ``t0``/``t1`` are seconds on the tracer's
     clock (wall or virtual); ``cls``/``node`` are the fixed dimensions
@@ -111,14 +131,14 @@ class Span:
     trace_id: int = -1           # -1: decision span (no request tree)
     cls: Optional[str] = None
     node: Optional[str] = None
-    attrs: dict = dataclasses.field(default_factory=dict)
+    attrs: Mapping = _NO_ATTRS
 
     @property
     def dur_ms(self) -> float:
         return (self.t1 - self.t0) * 1e3
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(slots=True)
 class RequestTrace:
     """One request's span tree (flat list; the root interval is
     ``t0 -> t1`` and the children partition it by component)."""
@@ -131,8 +151,9 @@ class RequestTrace:
     # span links: trace_ids of CAUSALLY-PRIOR attempts of the same
     # request (a retried/hedged/preempted request's second attempt links
     # to its first instead of starting an unrelated trace) — carried
-    # through the Perfetto export as event args
-    links: List[int] = dataclasses.field(default_factory=list)
+    # through the Perfetto export as event args; a tuple, so a tree with
+    # none shares the empty one
+    links: Tuple[int, ...] = ()
 
     @property
     def total_ms(self) -> float:
@@ -160,11 +181,15 @@ class Tracer:
     is reserved for the globally slowest requests (kept exactly, via a
     min-heap on total latency) and the rest holds a seeded uniform
     reservoir sample of the remainder — ``dropped`` counts evictions.
+    ``batch_cap`` bounds the retained batch spans (oldest evicted,
+    ``batches_dropped`` counts them).  The newest Tracer built is the
+    one :func:`current_tracer` returns.
     """
 
     def __init__(self, *, clock=time.perf_counter, cap: int = 4096,
                  tail_frac: float = 0.05, decision_cap: int = 8192,
-                 seed: int = 0):
+                 batch_cap: int = 65536, seed: int = 0):
+        global _current
         if cap < 2:
             raise ValueError("tracer cap must be >= 2")
         self.clock = clock
@@ -185,11 +210,15 @@ class Tracer:
         self.decision_cap = decision_cap
         self.decisions: Deque[Span] = collections.deque(maxlen=decision_cap)
         self.decisions_dropped = 0
+        self.batch_cap = batch_cap
+        self.batches: Deque[Span] = collections.deque(maxlen=batch_cap)
+        self.batches_dropped = 0
         # retirement hook: called with each FINALIZED RequestTrace (every
         # finished tree, whether or not sampling keeps it) — the streaming
         # exporter attaches here.  Always invoked OUTSIDE the tracer lock:
         # the callback may do file IO or call back into the tracer.
         self.on_retire = None
+        _current = self
 
     # --- request span trees --------------------------------------------------
 
@@ -202,7 +231,7 @@ class Tracer:
             self._open[rid] = RequestTrace(
                 trace_id=rid, cls=cls, node=node,
                 t0=self.clock() if t is None else t,
-                links=list(links))
+                links=tuple(links))
             return rid
 
     def add_span(self, trace_id: int, name: str, t0: float, t1: float, *,
@@ -213,7 +242,7 @@ class Tracer:
                 return   # request already ended/aborted: drop, don't raise
             tr.spans.append(Span(name=name, t0=t0, t1=t1, trace_id=trace_id,
                                  cls=tr.cls, node=node or tr.node,
-                                 attrs=attrs))
+                                 attrs=attrs or _NO_ATTRS))
 
     def end_request(self, trace_id: int, *, t: Optional[float] = None,
                     node: Optional[str] = None):
@@ -248,7 +277,9 @@ class Tracer:
             for name, s0, s1, attrs in spans:
                 tr.spans.append(Span(name=name, t0=s0, t1=s1,
                                      trace_id=trace_id, cls=tr.cls,
-                                     node=tr.node, attrs=dict(attrs or {})))
+                                     node=tr.node,
+                                     attrs=dict(attrs) if attrs
+                                     else _NO_ATTRS))
             tr.t1 = self.clock() if t is None else t
             self._retain(tr)
             cb = self.on_retire
@@ -296,11 +327,12 @@ class Tracer:
             rid = self._next_id
             self._next_id += 1
             tr = RequestTrace(trace_id=rid, cls=cls, t0=t0, t1=t1, node=node,
-                              links=list(links))
+                              links=tuple(links))
             for name, s0, s1, attrs in spans:
                 tr.spans.append(Span(name=name, t0=s0, t1=s1, trace_id=rid,
                                      cls=cls, node=node,
-                                     attrs=dict(attrs or {})))
+                                     attrs=dict(attrs) if attrs
+                                     else _NO_ATTRS))
             self._retain(tr)
             cb = self.on_retire
         if cb is not None:
@@ -339,11 +371,26 @@ class Tracer:
             t0 = self.clock()
         if t1 is None:
             t1 = t0
-        span = Span(name=name, t0=t0, t1=t1, cls=cls, node=node, attrs=attrs)
+        span = Span(name=name, t0=t0, t1=t1, cls=cls, node=node,
+                    attrs=attrs or _NO_ATTRS)
         with self._lock:
             if len(self.decisions) == self.decision_cap:
                 self.decisions_dropped += 1   # deque evicts the oldest
             self.decisions.append(span)
+        return span
+
+    # --- batch spans ---------------------------------------------------------
+
+    def batch(self, t0: float, t1: float, *, node: Optional[str] = None,
+              **attrs) -> Span:
+        """Record one dispatched batch (``t0`` first take, ``t1`` futures
+        resolved); ``node`` is also kept among the attrs."""
+        attrs["node"] = node
+        span = Span(name=BATCH, t0=t0, t1=t1, node=node, attrs=attrs)
+        with self._lock:
+            if len(self.batches) == self.batch_cap:
+                self.batches_dropped += 1     # deque evicts the oldest
+            self.batches.append(span)
         return span
 
     # --- reads ---------------------------------------------------------------
@@ -369,13 +416,29 @@ class Tracer:
             out.extend(self.decisions)
         return sorted(out, key=lambda s: (s.t0, s.t1, s.name))
 
+    def batch_spans(self) -> List[Span]:
+        """Retained batch spans, oldest first."""
+        with self._lock:
+            return list(self.batches)
+
     def summary(self) -> dict:
         with self._lock:
             return {"finished": self.finished, "aborted": self.aborted,
                     "retained": len(self._tail) + len(self._uniform),
                     "dropped": self.dropped,
                     "decisions": len(self.decisions),
-                    "decisions_dropped": self.decisions_dropped}
+                    "decisions_dropped": self.decisions_dropped,
+                    "batches": len(self.batches),
+                    "batches_dropped": self.batches_dropped}
+
+
+_current: Optional[Tracer] = None
+
+
+def current_tracer() -> Optional[Tracer]:
+    """The Tracer most recently built in this process (None before the
+    first), held until another is built."""
+    return _current
 
 
 def validate_schema(spans: Iterable[Span]) -> List[str]:

@@ -87,6 +87,7 @@ class _InFlight:
     bucket: int = 0                   # (SubnetSpec, bucket) executable
     t_collect: float = 0.0     # obs: batch window closed (stacking starts)
     t_disp_ret: float = 0.0    # obs: async dispatch call returned
+    cpu_stack_s: float = 0.0   # obs: collector CPU over collect->disp_ret
 
 
 @guarded_by("_wake_lock", "_wake_tokens")
@@ -135,7 +136,12 @@ class DynamicServer:
         pass ``trace_id`` to :meth:`submit`, or the engine begins its
         own under the tenant label.  ``metrics`` (a
         :class:`repro.obs.MetricsRegistry`) gets served/cancelled
-        counters and a request-latency histogram.  Both default to None
+        counters and a request-latency histogram.  With a tracer, each
+        batch also records one ``batch`` span (stage stamps, the
+        requests' submit/take times, the collector's CPU time over stack
+        + dispatch), and stack + dispatch run inside a
+        ``jax.profiler.TraceAnnotation("engine.batch")``, so a profiler
+        that records host events places them.  Both default to None
         = zero work on the hot path; the cluster layer also sets them
         post-construction (``trace_node`` labels spans with the node).
         """
@@ -475,8 +481,21 @@ class DynamicServer:
             self._pad_pool[key].append(buf)
 
     def _dispatch(self, reqs: List[Request]) -> _InFlight:
-        """Stack + pad to the nearest bucket and dispatch asynchronously."""
-        t_collect = time.perf_counter() if self.tracer is not None else 0.0
+        """Stack + pad to the nearest bucket and dispatch asynchronously;
+        traced, inside a profiler annotation."""
+        if self.tracer is None:
+            return self._stack_dispatch(reqs, False)
+        with jax.profiler.TraceAnnotation(
+                "engine.batch", node=self.trace_node or "",
+                bucket=self._bucket_for(len(reqs))):
+            return self._stack_dispatch(reqs, True)
+
+    def _stack_dispatch(self, reqs: List[Request], traced: bool) -> _InFlight:
+        if traced:
+            t_collect = time.perf_counter()
+            cpu0 = time.thread_time()
+        else:
+            t_collect = 0.0
         xs = [np.asarray(r.x) for r in reqs]
         n = len(xs)
         bucket = self._bucket_for(n)
@@ -497,16 +516,24 @@ class DynamicServer:
             _DISPATCH_NOTE()
         t_disp = time.perf_counter()
         out = fn(self.params, buf)       # async: returns before ready
-        t_ret = time.perf_counter() if self.tracer is not None else 0.0
+        if traced:
+            cpu = time.thread_time() - cpu0
+            t_ret = time.perf_counter()
+        else:
+            cpu = t_ret = 0.0
         return _InFlight(out=out, reqs=reqs, t_dispatch=t_disp, hw=hw,
                          subnet=spec.name(), buf_key=buf_key, buf=buf,
                          spec=spec, bucket=bucket,
-                         t_collect=t_collect, t_disp_ret=t_ret)
+                         t_collect=t_collect, t_disp_ret=t_ret,
+                         cpu_stack_s=cpu)
 
     def _complete(self, item: _InFlight):
         """Resolve one in-flight batch: wait for the device, account the
         non-overlapping dispatch->ready interval, answer the futures."""
-        out = np.asarray(jax.block_until_ready(item.out))
+        out = jax.block_until_ready(item.out)
+        # device-ready, apart from the copy of the outputs to the host
+        t_ready_dev = time.perf_counter() if self.tracer is not None else 0.0
+        out = np.asarray(out)
         if item.buf is not None:
             self._give_buffer(item.buf_key, item.buf)
             item.buf = None          # _complete_safe must not re-pool it
@@ -558,6 +585,16 @@ class DynamicServer:
                          None),
                         (obs.DEVICE, item.t_disp_ret, t_ready, dev_attrs),
                         (obs.COMPLETE, t_ready, t_done, None)])
+            reqs = item.reqs
+            self.tracer.batch(
+                reqs[0].t_take, t_done, node=self.trace_node,
+                bucket=item.bucket, subnet=item.subnet, n=len(reqs),
+                t_take_first=reqs[0].t_take, t_collect=item.t_collect,
+                t_dispatch=item.t_dispatch, t_disp_ret=item.t_disp_ret,
+                t_ready_dev=t_ready_dev, t_ready=t_ready, t_done=t_done,
+                submit=tuple(r.t_submit for r in reqs),
+                take=tuple(r.t_take for r in reqs),
+                cpu_stack_s=item.cpu_stack_s)
         if self.metrics is not None:
             tn = self.tenant or "default"
             nd = self.trace_node or ""
