@@ -3,9 +3,12 @@
 Everything is found by name from ``BENCHMARK.json`` at the checkout's
 root: the workload names its configuration (``configs[].file``), its
 traffic mix (``bench/traffic/<traffic>.json``, whose ``kind`` names the
-generator ``bench/traffic/<kind>.py``) and the chips it needs; each metric
-it reports is read by ``bench/metrics/<metric>.py``.  A new cell, mix or
-metric is new files and entries, and no edit here.
+generator ``bench/traffic/<kind>.py``) and the chips it needs; the
+configuration names its model family (``bench/families/<family>.py``,
+through which alone the harness reads what is particular to a model:
+see ``FAMILY_API``); each metric it reports is read by
+``bench/metrics/<metric>.py``.  A new cell, mix, model family or metric
+is new files and entries, and no edit here.
 
 A run: check the device; build the servers through the serving code's
 own entry point with the benchmark's seeded weights swapped in; measure
@@ -30,13 +33,19 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from bench import flops, stats
+from bench import stats
 
 ROOT = Path(__file__).resolve().parent.parent
 
 POOL, WEIGHTS, SAMPLE = 3, 4, 5          # seed streams of a run
 TRACE_SECONDS = 3.0                      # profiler window, mid-run
 TRACER_CAP = 1 << 19                     # keeps every request's spans
+
+# what a model family's file provides (``bench/families/vit.py`` says
+# what each name is)
+FAMILY_API = ("FULL", "model_of", "make_weights", "check_layout", "pool",
+              "reference", "rel_err", "flops_per_request", "roofline_s",
+              "spec")
 
 
 class BenchError(SystemExit):
@@ -70,12 +79,23 @@ def load_module(path: Path):
     return mod
 
 
+def load_family(root: Path, name: str):
+    """The model family ``name`` (``bench/families/<name>.py``); refuses
+    one that lacks a name of ``FAMILY_API``."""
+    fam = load_module(root / "bench" / "families" / f"{name}.py")
+    missing = [k for k in FAMILY_API if not hasattr(fam, k)]
+    if missing:
+        raise BenchError(f"model family {name!r} lacks {missing}")
+    return fam
+
+
 @dataclasses.dataclass
 class Cell:
     workload: dict
     config: dict
     mix: dict
     gen: object
+    family: object
     end_to_end: List[dict]
     per_layer: List[dict]
     root: Path
@@ -99,6 +119,9 @@ def find_cell(name: str, root: Path = ROOT) -> Cell:
     if cfg_entry is None:
         raise BenchError(f"workload {name!r}: no config {wl['config']!r}")
     config = load_json(root / cfg_entry["file"])
+    if "family" not in config:
+        raise BenchError(f"{cfg_entry['file']} names no model family")
+    family = load_family(root, config["family"])
     mix = load_json(root / "bench" / "traffic" / f"{wl['traffic']}.json")
     gen = load_module(root / "bench" / "traffic" / f"{mix['kind']}.py")
     e2e = [m for m in bench["end_to_end"]
@@ -107,7 +130,7 @@ def find_cell(name: str, root: Path = ROOT) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if name in m.get("workloads", [name] if m["moves"] in moved
                                   else [])]
-    return Cell(wl, config, mix, gen, e2e, per_layer, root)
+    return Cell(wl, config, mix, gen, family, e2e, per_layer, root)
 
 
 # --- the device --------------------------------------------------------------
@@ -182,8 +205,12 @@ class Run:
     def model(self) -> dict:
         return self.cell.config["model"]
 
+    @property
+    def family(self):
+        return self.cell.family
+
     def flops(self, subnet: str) -> float:
-        return flops.flops_per_image(self.model, subnet)
+        return self.family.flops_per_request(self.model, subnet)
 
 
 def batches_of(tracer) -> List[dict]:
@@ -210,27 +237,16 @@ def batches_of(tracer) -> List[dict]:
 
 # --- a run -------------------------------------------------------------------
 
-def model_of(cfg) -> dict:
-    return {k: getattr(cfg, k) for k in (
-        "img_res", "patch", "n_layers", "d_model", "n_heads", "d_ff",
-        "n_classes", "distill_token", "exit_layers", "param_dtype",
-        "compute_dtype")}
-
-
-def check_model(cfg, model: dict):
+def check_model(family, cfg, model: dict):
     """The configuration file has to state what the program runs."""
-    theirs = json.loads(json.dumps(model_of(cfg)))
+    theirs = json.loads(json.dumps(family.model_of(cfg)))
     if theirs != model:
         raise BenchError(f"config file model {model} is not what the "
                          f"program builds: {theirs}")
 
 
-def spec_of(name: str):
-    from repro.core.types import SubnetSpec
-    from bench import subnet
-    m = subnet.parse(name)
-    spec = SubnetSpec(width_mult=m["width"], ffn_mult=m["ffn"],
-                      heads_mult=m["heads"], depth_mult=m["depth"])
+def spec_of(family, name: str):
+    spec = family.spec(name)
     if spec.name() != name:
         raise BenchError(f"sub-network {name!r} reads back as {spec.name()}")
     return spec
@@ -370,7 +386,7 @@ class Session:
         arch = get_arch(config["arch"])
         cfg = arch.make_config()
         self.model = config["model"]
-        check_model(cfg, self.model)
+        check_model(cell.family, cfg, self.model)
         self.used = devices[:chips]
         self.counter = CompileCounter()
         self.servers = []
@@ -381,7 +397,7 @@ class Session:
                 plant(srv)
             self.servers.append(srv)
         self.reseed(seed)
-        specs = [spec_of(n) for n in config["subnets"]]
+        specs = [spec_of(cell.family, n) for n in config["subnets"]]
         for srv in self.servers:
             srv.warm(specs, example_input=self.pool[0])
         # timed once the cell's programs are built and have run, so the
@@ -427,17 +443,15 @@ class Session:
     def reseed(self, seed: int):
         """The seed's weights (swapped into every server) and input pool."""
         import jax
-        from bench import weights
-        self.w = weights.make(self.model, stats.seed32(seed, WEIGHTS),
-                              self.used[0])
+        fam = self.cell.family
+        self.w = fam.make_weights(self.model, stats.seed32(seed, WEIGHTS),
+                                  self.used[0])
         for dev, srv in zip(self.used, self.servers):
-            weights.check_layout(self.w, srv.params)
+            fam.check_layout(self.w, srv.params)
             srv.params = self.w if dev == self.used[0] \
                 else jax.device_put(self.w, dev)
         rng = np.random.default_rng(stats.seed32(seed, POOL))
-        res = self.model["img_res"]
-        self.pool = rng.standard_normal(
-            (self.cell.mix["pool"], res, res, 3), np.float32)
+        self.pool = fam.pool(self.model, self.cell.mix["pool"], rng)
 
     def drive(self, seconds: float, seed: int, mix: Optional[dict] = None
               ) -> Run:
@@ -530,8 +544,10 @@ def sample_answers(cell: Cell, win, seed: int) -> Dict[str, List[int]]:
 
 
 def check_answers(cell: Cell, run: Run, w, pool, seed: int) -> Dict:
-    """Compare a seeded sample of served answers with the reference."""
-    from bench import reference
+    """Compare a seeded sample of served answers with the family's plain
+    reference; ``rel_err`` reduces each answer, of whatever shape, to one
+    number."""
+    fam = cell.family
     win, config = run.window, cell.config
     known = set(config["subnets"])
     unknown = sum(1 for k in range(len(win.out)) if win.error[k] is None
@@ -539,9 +555,8 @@ def check_answers(cell: Cell, run: Run, w, pool, seed: int) -> Dict:
     sample = sample_answers(cell, win, seed)
     worst, worst_at = 0.0, None
     for name, ks in sample.items():
-        ref = reference.logits(w, pool[win.idx[ks]], config["model"], name)
-        ys = np.stack([np.asarray(win.out[k], np.float32) for k in ks])
-        errs = reference.rel_err(ys, ref)
+        ref = fam.reference(w, pool[win.idx[ks]], config["model"], name)
+        errs = fam.rel_err([win.out[k] for k in ks], ref)
         j = int(np.argmax(errs))
         if errs[j] > worst:
             worst, worst_at = float(errs[j]), (name, ks[j])

@@ -6,11 +6,12 @@
 One process sets the cell up once; for each seed it swaps in that seed's
 weights and inputs, drives a short window at the cell's own load, and
 takes the sample a run compares.  Per seed it prints the program's
-reading (largest |served - reference| / largest |reference| over the
-sample) and the control's: the plain reference computed with float8
-e4m3 matrix products, on the same inputs and sub-networks.  The limit
-goes above the largest program reading and below the smallest control
-reading (PERF.md gives both).
+reading (the cell's model family's ``rel_err``, worst over the sample)
+and the control's: the family's plain reference at the precision below
+the served one (``fp8=True``; for ``vit``, float8 e4m3 matrix products),
+on the same inputs and sub-networks.  The limit goes above the largest
+program reading and below the smallest control reading (PERF.md gives
+both).
 """
 from __future__ import annotations
 
@@ -27,19 +28,19 @@ sys.path[0:1] = [str(ROOT), str(ROOT / "src")]
 
 import numpy as np  # noqa: E402
 
-from bench import harness, reference  # noqa: E402
+from bench import harness  # noqa: E402
 
 
 def readings(cell, session, run, seed: int) -> dict:
-    win, model = run.window, cell.config["model"]
+    win, model, fam = run.window, cell.config["model"], cell.family
     prog, ctrl = 0.0, 0.0
     for name, ks in harness.sample_answers(cell, win, seed).items():
         x = session.pool[win.idx[ks]]
-        ref = reference.logits(session.w, x, model, name)
-        ys = np.stack([np.asarray(win.out[k], np.float32) for k in ks])
-        prog = max(prog, float(reference.rel_err(ys, ref).max()))
-        c = reference.logits(session.w, x, model, name, fp8=True)
-        ctrl = max(ctrl, float(reference.rel_err(c, ref).max()))
+        ref = fam.reference(session.w, x, model, name)
+        ys = [win.out[k] for k in ks]
+        prog = max(prog, float(np.max(fam.rel_err(ys, ref))))
+        c = fam.reference(session.w, x, model, name, fp8=True)
+        ctrl = max(ctrl, float(np.max(fam.rel_err(c, ref))))
     return {"seed": seed, "program": prog, "control_fp8": ctrl,
             "failed": int(np.count_nonzero(win.failed)),
             "subnets": sorted(set(s for s in win.subnet if s))}

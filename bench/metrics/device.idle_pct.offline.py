@@ -1,5 +1,5 @@
 """Share of the traced window with no operation on the device, averaged
-over the four chips."""
+over the cell's chips."""
 from bench import readers
 
 

@@ -1,5 +1,5 @@
 """The collector threads' CPU time over the wall time of stack + dispatch
-(batch spans' ``cpu_stack_s``), over the four replicas' batches stacked
+(batch spans' ``cpu_stack_s``), over every replica's batches stacked
 inside the window: low means the collectors waited."""
 from bench import spans
 

@@ -2,12 +2,10 @@
 that served each one, from its exact FLOPs over full width's."""
 from bench import stats
 
-FULL = "w1-f1-h1-d1"
-
 
 def read(run):
     w = run.window
-    full = run.flops(FULL)
+    full = run.flops(run.family.FULL)
     acc = {}
     vals = []
     for k, name in enumerate(w.subnet):

@@ -41,24 +41,22 @@ def full_width_roofline(run, log=None) -> Optional[float]:
     """Share of its roofline that the full-width executable with the most
     device time reached in the traced window: the least time the chip
     could take for one call (FLOPs over peak, or compulsory bytes over
-    HBM bandwidth, whichever is larger) over its mean device time."""
-    from bench import flops
+    HBM bandwidth, whichever is larger, from the cell's model family)
+    over its mean device time."""
     p = run.profile
     if p is None:
         return None
+    fam = run.family
     full = [(v["device_s"], v) for v in p["programs"].values()
-            if v["label"] and v["label"][0] == FULL and v["runs"]]
+            if v["label"] and v["label"][0] == fam.FULL and v["runs"]]
     if not full:
         return None
     _, prog = max(full, key=lambda kv: kv[0])
     bucket = prog["label"][1]
-    t_min, bound = flops.roofline_s(run.model, FULL, bucket, run.peak)
+    t_min, bound = fam.roofline_s(run.model, fam.FULL, bucket, run.peak)
     mean = prog["device_s"] / prog["runs"]
     if log is not None:
         log(f"full width bucket {bucket}: {prog['runs']} runs, mean "
             f"{mean * 1e3:.4f} ms on the device; roofline "
             f"{t_min * 1e3:.4f} ms, {bound}-bound")
     return 100.0 * t_min / mean
-
-
-FULL = "w1-f1-h1-d1"

@@ -6,7 +6,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import subnet
+from bench import harness
 
 ROOT = Path(__file__).resolve().parent.parent
 B = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -61,8 +61,9 @@ def test_configs_and_cells():
         conf = json.loads(path.read_text())
         assert conf["reduced"] == c["reduced"]
         assert isinstance(conf["correct"]["max_rel_err"], float)
+        family = harness.load_family(ROOT, conf["family"])
         for name in conf["subnets"]:
-            subnet.dims(conf["model"], name)
+            family.flops_per_request(conf["model"], name)
     pairs = {(w["config"], w["traffic"]) for w in cells.values()}
     assert len(pairs) == len(cells)
     four = sum(w["chips"] == 4 for w in cells.values())
@@ -100,3 +101,34 @@ def test_metrics():
         assert "setup_s" in [m["name"] for m in reported]
         assert len(reported) >= 2
         assert any(cell in m["workloads"] for m in B["per_layer"])
+
+
+@pytest.mark.parametrize("entry", B["configs"], ids=lambda c: c["name"])
+def test_config_names_a_family_with_the_whole_contract(entry):
+    conf = json.loads((ROOT / entry["file"]).read_text())
+    assert NAME.fullmatch(conf["family"])
+    assert (ROOT / "bench" / "families" / f"{conf['family']}.py").is_file()
+    # refuses a family that lacks a name of the contract
+    harness.load_family(ROOT, conf["family"])
+
+
+def test_a_family_without_the_whole_contract_is_refused(tmp_path):
+    src = (ROOT / "bench" / "families" / "vit.py").read_text()
+    fam = tmp_path / "bench" / "families" / "partial.py"
+    fam.parent.mkdir(parents=True)
+    fam.write_text(src + "\ndel rel_err\n")
+    with pytest.raises(harness.BenchError, match="rel_err"):
+        harness.load_family(tmp_path, "partial")
+    with pytest.raises(harness.BenchError, match="missing"):
+        harness.load_family(tmp_path, "absent")
+
+
+# what only a model family may read: the ViT's image and token layout
+MODEL_WORDS = re.compile(r"\b(img_res|patch|distill_token)\b")
+
+
+@pytest.mark.parametrize("path", sorted(
+    p.relative_to(ROOT).as_posix() for p in (ROOT / "bench").rglob("*.py")
+    if "families" not in p.parts and not p.name.startswith("test_")))
+def test_only_a_family_reads_the_model_layout(path):
+    assert not MODEL_WORDS.findall((ROOT / path).read_text()), path

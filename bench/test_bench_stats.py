@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from bench import loop, stats
+from bench.families import vit
 from bench.harness import ROOT, Run, load_module
 
 
@@ -24,7 +25,7 @@ def window(due, reply, errors=None, subnets=None):
 
 
 def run_of(win, deadline=None, seconds=10.0):
-    cell = types.SimpleNamespace(config={"model": {
+    cell = types.SimpleNamespace(family=vit, config={"model": {
         "img_res": 224, "patch": 16, "n_layers": 12, "d_model": 384,
         "n_heads": 6, "d_ff": 1536, "n_classes": 1000,
         "distill_token": False}})
